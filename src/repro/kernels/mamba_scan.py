@@ -1,14 +1,18 @@
 """Mamba-1 selective-scan Pallas TPU kernel.
 
-TPU adaptation of the hardware-aware CUDA scan: the recurrent state
-h (d_block x N) is VMEM scratch carried across the sequential chunk grid
-dimension; the discretized (C x d_block x N) tensors exist only in VMEM,
-one chunk at a time — HBM traffic is dt/x (C x d_block), B/C (C x N) in
-and y (C x d_block) out, never the O(T x d x N) expansion.
+TPU adaptation of the hardware-aware CUDA scan: the recurrent state h is
+VMEM scratch carried across the sequential chunk grid dimension, and the
+chunk's rows are folded into it one at a time by a ``fori_loop``. HBM
+traffic is dt/x (C x d_block), B/C (C x N) in and y (C x d_block) out,
+never the O(T x d x N) expansion.
 
 Grid: (batch, d_inner/d_block, T/C). d_inner is tiled so arbitrarily wide
-models (jamba: 16384) keep the VMEM working set fixed; lane dim is the
-SSM state N (16) padded into the (8,128)-tile by the compiler.
+models (jamba: 16384) keep the VMEM working set fixed. Inside the kernel
+the state is laid out (N, d_block): d_block on the lanes and the SSM state
+N (16) on the sublanes, so no (C x d_block x N) tensor is built; with N on
+the lanes it would be padded 8x to the 128-lane tile and overflow VMEM.
+B and C rows become (N, 1) columns through a small VMEM scratch, because
+Mosaic cannot slice a value at a dynamic row.
 """
 
 from __future__ import annotations
@@ -22,39 +26,32 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _mamba_kernel(
-    dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, hout_ref, h_scr,
-    *, chunk: int, nchunks: int,
+    dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, hout_ref,
+    h_scr, b_scr, c_scr, *, chunk: int, nchunks: int,
 ):
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
     def _init():
-        h_scr[...] = h0_ref[0]
+        h_scr[...] = h0_ref[0].astype(jnp.float32).T
 
-    dt = dt_ref[0].astype(jnp.float32)  # (C, Db)
-    x = x_ref[0].astype(jnp.float32)  # (C, Db)
-    bmat = b_ref[0].astype(jnp.float32)  # (C, N)
-    cmat = c_ref[0].astype(jnp.float32)  # (C, N)
-    a = a_ref[...].astype(jnp.float32)  # (Db, N)
+    a = a_ref[...].astype(jnp.float32).T  # (N, Db)
+    b_scr[...] = b_ref[0].astype(jnp.float32)[:, :, None]  # (C, N, 1)
+    c_scr[...] = c_ref[0].astype(jnp.float32)[:, :, None]
 
-    da = jnp.exp(dt[:, :, None] * a[None, :, :])  # (C, Db, N)
-    dbx = (dt * x)[:, :, None] * bmat[:, None, :]  # (C, Db, N)
+    def row(i, h):
+        dt = dt_ref[0, pl.ds(i, 1), :].astype(jnp.float32)  # (1, Db)
+        x = x_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        h = jnp.exp(dt * a) * h + (dt * x) * b_scr[i]  # (N, Db)
+        y = jnp.sum(h * c_scr[i], axis=0, keepdims=True)  # (1, Db)
+        y_ref[0, pl.ds(i, 1), :] = y.astype(y_ref.dtype)
+        return h
 
-    # intra-chunk associative scan over time (log-depth on the VPU)
-    def combine(e1, e2):
-        a1, b1 = e1
-        a2, b2 = e2
-        return a1 * a2, a2 * b1 + b2
-
-    acc_a, acc_b = jax.lax.associative_scan(combine, (da, dbx), axis=0)
-    h_all = acc_a * h_scr[...][None] + acc_b  # (C, Db, N)
-    y = jnp.sum(h_all * cmat[:, None, :], axis=2)  # (C, Db)
-    h_scr[...] = h_all[-1]
-    y_ref[0] = y.astype(y_ref.dtype)
+    h_scr[...] = jax.lax.fori_loop(0, chunk, row, h_scr[...])
 
     @pl.when(ic == nchunks - 1)
     def _final():
-        hout_ref[0] = h_scr[...].astype(hout_ref.dtype)
+        hout_ref[0] = h_scr[...].T.astype(hout_ref.dtype)
 
 
 def mamba_chunk_scan_b(
@@ -96,7 +93,11 @@ def mamba_chunk_scan_b(
             jax.ShapeDtypeStruct((bsz, t, di), jnp.float32),
             jax.ShapeDtypeStruct((bsz, di, n), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((d_block, n), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((n, d_block), jnp.float32),
+            pltpu.VMEM((chunk, n, 1), jnp.float32),
+            pltpu.VMEM((chunk, n, 1), jnp.float32),
+        ],
         interpret=interpret,
     )(dt, x, bmat, cmat, a, h0)
     return y, hout

@@ -1,7 +1,8 @@
 """Jit'd wrappers: model-facing shapes -> kernel layouts (+ auto interpret).
 
-``interpret`` defaults to True off-TPU so the same call sites run the
-kernel bodies in Python on CPU (correctness) and compile natively on TPU.
+``interpret`` is True on the CPU backend only, so the same call sites run
+the kernel bodies in Python on CPU (correctness) and compile natively on
+TPU. Any other backend compiles too, and fails loudly if it cannot.
 """
 
 from __future__ import annotations
@@ -17,7 +18,7 @@ from .rwkv6 import rwkv6_chunked_bh
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k"))

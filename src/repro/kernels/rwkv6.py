@@ -36,15 +36,21 @@ def _wkv_kernel(
     lw = w_ref[0].astype(jnp.float32)  # (C, K) = log decay, <= 0
     u = u_ref[0].astype(jnp.float32)  # (1, K) bonus
 
-    cum = jnp.cumsum(lw, axis=0)  # (C, K)
+    # Inclusive prefix sum over time as a lower-triangular matmul (Mosaic
+    # has no cumsum). HIGHEST keeps the f32 log-decays out of bf16 passes.
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    lower = (row >= col).astype(jnp.float32)
+    cum = jnp.dot(lower, lw, preferred_element_type=jnp.float32,
+                  precision=jax.lax.Precision.HIGHEST)  # (C, K)
     cum_prev = cum - lw
 
     # Intra-chunk pairwise scores: A[t, s] = sum_k r[t]k[s]exp(cum_prev[t]-cum[s])
     diff = cum_prev[:, None, :] - cum[None, :, :]  # (C, C, K), <= 0 for s < t
-    tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) > \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    ratio = jnp.where(tri[:, :, None], jnp.exp(diff), 0.0)
-    scores = jnp.einsum("tk,sk,tsk->ts", r, k, ratio)  # (C, C)
+    tri = jax.lax.broadcasted_iota(jnp.int32, diff.shape, 0) > \
+        jax.lax.broadcasted_iota(jnp.int32, diff.shape, 1)
+    ratio = jnp.where(tri, jnp.exp(diff), 0.0)
+    scores = jnp.sum(r[:, None, :] * k[None, :, :] * ratio, axis=-1)  # (C, C)
     diag = jnp.sum(r * u * k, axis=1)  # (C,) bonus term
     out = jnp.dot(scores, v, preferred_element_type=jnp.float32)
     out = out + diag[:, None] * v
@@ -55,8 +61,8 @@ def _wkv_kernel(
     out = out + jnp.dot(rw, s0, preferred_element_type=jnp.float32)
 
     # State update: S' = diag(exp(cum_C)) S + sum_s exp(cum_C - cum_s) k_s v_s
-    tail = jnp.exp(cum[-1][None, :] - cum)  # (C, K)
-    s_scr[...] = jnp.exp(cum[-1])[:, None] * s0 + jnp.dot(
+    tail = jnp.exp(cum[chunk - 1:chunk] - cum)  # (C, K)
+    s_scr[...] = jnp.exp(cum[chunk - 1])[:, None] * s0 + jnp.dot(
         (k * tail).T, v, preferred_element_type=jnp.float32
     )
 

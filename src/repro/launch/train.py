@@ -32,7 +32,10 @@ def main() -> None:
     from repro.core import Colonies, Crypto, FunctionSpec, InProcTransport
     from repro.core.cluster import standalone_server
     from repro.core.fs import MemoryStorage
+    from repro.launch.compile_cache import place_compile_cache
     from repro.runtime.jax_executor import TrainerExecutor
+
+    place_compile_cache()
 
     server_prv, colony_prv = Crypto.prvkey(), Crypto.prvkey()
     server = standalone_server(Crypto.id(server_prv))

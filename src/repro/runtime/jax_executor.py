@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..configs import TrainConfig, get_config
+from ..configs import ModelConfig, TrainConfig, get_config
 from ..core.client import Colonies
 from ..core.errors import ValidationError
 from ..core.executor import ExecutorBase, ProcessContext
@@ -102,11 +102,17 @@ class JaxExecutorBase(ExecutorBase):
                 )
 
 
-def _smoke_cfg(kwargs: dict):
-    cfg = get_config(kwargs["arch"], kwargs.get("variant", "smoke"))
-    # CPU smoke numerics
-    return cfg.copy(param_dtype="float32", compute_dtype="float32",
-                    use_pallas=bool(kwargs.get("use_pallas", False)))
+def executor_config(arch: str, variant: str = "smoke", use_pallas: bool = False) -> ModelConfig:
+    """The model an executor builds: ``smoke`` in float32 (CPU tests);
+    every other variant in the config's own parameter and compute dtypes."""
+    cfg = get_config(arch, variant)
+    if variant == "smoke":
+        cfg = cfg.copy(param_dtype="float32", compute_dtype="float32")
+    return cfg.copy(use_pallas=use_pallas)
+
+
+def _init_params(cfg: ModelConfig, seed: int):
+    return init_params(jax.random.key(seed), model_spec(cfg), jnp.dtype(cfg.param_dtype))
 
 
 class TrainerExecutor(JaxExecutorBase):
@@ -120,7 +126,8 @@ class TrainerExecutor(JaxExecutorBase):
 
     # ------------------------------------------------------------------ train
     def train(self, ctx: ProcessContext, **kw: Any) -> list[Any]:
-        cfg = _smoke_cfg(kw)
+        cfg = executor_config(kw["arch"], kw.get("variant", "smoke"),
+                              bool(kw.get("use_pallas", False)))
         steps = int(kw.get("steps", 10))
         batch_size = int(kw.get("batch", 4))
         seq_len = int(kw.get("seq_len", 64))
@@ -137,7 +144,7 @@ class TrainerExecutor(JaxExecutorBase):
         ckpt = CheckpointManager(self.cfs, self.colonyname, run=run)
         data = SyntheticTokens(cfg, batch_size, seq_len, seed=tcfg.seed)
 
-        params = init_params(jax.random.key(tcfg.seed), model_spec(cfg), jnp.float32)
+        params = _init_params(cfg, tcfg.seed)
         state = init_state(params, tcfg)
         start = 0
         restored = ckpt.restore_latest(state)
@@ -160,14 +167,15 @@ class TrainerExecutor(JaxExecutorBase):
 
     # --------------------------------------------------------------- evaluate
     def evaluate(self, ctx: ProcessContext, **kw: Any) -> list[Any]:
-        cfg = _smoke_cfg(kw)
+        cfg = executor_config(kw["arch"], kw.get("variant", "smoke"),
+                              bool(kw.get("use_pallas", False)))
         run = kw.get("run", "run0")
         batch_size = int(kw.get("batch", 4))
         seq_len = int(kw.get("seq_len", 64))
         batches = int(kw.get("eval_batches", 2))
         tcfg = TrainConfig(seed=int(kw.get("seed", 0)))
         ckpt = CheckpointManager(self.cfs, self.colonyname, run=run)
-        params = init_params(jax.random.key(tcfg.seed), model_spec(cfg), jnp.float32)
+        params = _init_params(cfg, tcfg.seed)
         state = init_state(params, tcfg)
         restored = ckpt.restore_latest(state)
         if restored is None:
@@ -185,14 +193,14 @@ class TrainerExecutor(JaxExecutorBase):
 class ServeExecutor(JaxExecutorBase):
     """Hosts a ServeEngine; handles generator-fired ``generate_batch``."""
 
-    def __init__(self, *args: Any, arch: str = "stablelm-3b", max_len: int = 128,
-                 run: str | None = None, **kw: Any) -> None:
+    def __init__(self, *args: Any, arch: str = "stablelm-3b", variant: str = "smoke",
+                 max_len: int = 128, run: str | None = None, **kw: Any) -> None:
         super().__init__(*args, **kw)
         from ..serve.batcher import make_batch_handler
         from ..serve.engine import ServeEngine
 
-        cfg = _smoke_cfg({"arch": arch})
-        params = init_params(jax.random.key(0), model_spec(cfg), jnp.float32)
+        cfg = executor_config(arch, variant)
+        params = _init_params(cfg, 0)
         if run is not None:  # serve a trained checkpoint (continuum hand-off)
             from ..train.train_step import init_state as _init
 

@@ -8,7 +8,7 @@ except for the final token fetch.
 
 from __future__ import annotations
 
-from functools import partial
+import time
 from typing import Any
 
 import jax
@@ -54,7 +54,22 @@ class ServeEngine:
         self._prefill = jax.jit(make_prefill(cfg, max_len))
         self._decode = jax.jit(make_serve_step(cfg))
         self._sample = jax.jit(sample_token, static_argnums=(2,))
-        self.stats = {"requests": 0, "tokens": 0, "batches": 0}
+        self.stats = {"requests": 0, "tokens": 0, "batches": 0, "seconds": 0.0}
+
+    def warmup(self, batch: int, prompt_len: int, max_new_tokens: int) -> dict[str, float]:
+        """Compile everything one batch shape needs, so that serving it
+        compiles nothing; returns the seconds of the first prefill and the
+        first decode call (compilation included)."""
+        tokens = np.zeros((batch, prompt_len), np.int32)
+        t0 = time.perf_counter()
+        logits, cache = jax.block_until_ready(
+            self._prefill(self.params, {"tokens": jnp.asarray(tokens)}))
+        t1 = time.perf_counter()
+        tok = self._sample(logits, jax.random.key(0), 0.0)
+        jax.block_until_ready(self._decode(self.params, tok, cache, jnp.int32(prompt_len)))
+        t2 = time.perf_counter()
+        self._generate(tokens, max_new_tokens)  # the loop's small eager programs
+        return {"prefill_s": t1 - t0, "decode_s": t2 - t1}
 
     def generate(
         self,
@@ -64,24 +79,31 @@ class ServeEngine:
         seed: int = 0,
         extras: dict | None = None,
     ) -> np.ndarray:
+        b, s = tokens.shape
+        assert s + max_new_tokens <= self.max_len, "increase max_len"
+        t0 = time.perf_counter()
+        out = self._generate(tokens, max_new_tokens, temperature, seed, extras)
+        self.stats["requests"] += b
+        self.stats["tokens"] += b * max_new_tokens
+        self.stats["batches"] += 1
+        self.stats["seconds"] += time.perf_counter() - t0
+        return out
+
+    def _generate(self, tokens: np.ndarray, max_new_tokens: int, temperature: float = 0.0,
+                  seed: int = 0, extras: dict | None = None) -> np.ndarray:
         batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
         if extras:
             batch.update({k: jnp.asarray(v) for k, v in extras.items()})
-        b, s = tokens.shape
-        assert s + max_new_tokens <= self.max_len, "increase max_len"
         logits, cache = self._prefill(self.params, batch)
         rng = jax.random.key(seed)
         out = []
         tok = self._sample(logits, rng, temperature)
         out.append(tok)
-        pos = s
+        pos = tokens.shape[1]
         for i in range(max_new_tokens - 1):
             rng, sub = jax.random.split(rng)
             logits, cache = self._decode(self.params, tok, cache, jnp.int32(pos))
             tok = self._sample(logits, sub, temperature)
             out.append(tok)
             pos += 1
-        self.stats["requests"] += b
-        self.stats["tokens"] += b * max_new_tokens
-        self.stats["batches"] += 1
         return np.asarray(jnp.concatenate(out, axis=1))

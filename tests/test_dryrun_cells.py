@@ -30,7 +30,7 @@ _SCRIPT = textwrap.dedent(
 def _run_cells(cells, overrides=None):
     script = _SCRIPT.format(cells=repr(cells), overrides=repr(overrides or {}))
     env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # the child wants host devices, not a chip
     out = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         env=env, timeout=1200, cwd=ROOT,
