@@ -1,0 +1,8 @@
+"""Device (TPU v5e): the share of the traced window in which no program
+ran on the chip."""
+
+
+def read(run):
+    if run.trace is None or run.trace["window_s"] <= 0:
+        return None
+    return 1.0 - run.trace["busy_s"] / run.trace["window_s"]
