@@ -57,7 +57,7 @@ import time
 
 # Families that must guard only straight-line, non-blocking code: nothing
 # may be acquired while one is held.
-LEAF_FAMILIES = frozenset({"glock"})
+LEAF_FAMILIES = frozenset({"glock", "tracing"})
 
 # Families whose instances never legitimately nest with each other
 # (per-colony shards, per-colony CFS shards, per-colony server locks,

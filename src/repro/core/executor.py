@@ -22,6 +22,7 @@ import zlib
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+from . import tracing
 from .client import Colonies
 from .crypto import Crypto
 from .errors import (
@@ -137,12 +138,16 @@ class ExecutorBase:
     # ------------------------------------------------------------ main loop
     def step(self, timeout: float = 1.0) -> bool:
         """One assign+execute+close cycle; returns True if a process ran."""
-        try:
-            pd = self.client.assign(self.colonyname, timeout, self.prvkey)
-        except (TimeoutError_, NotLeaderError):
-            return False
+        with tracing.span("colony.executor.assign") as sp:
+            try:
+                pd = self.client.assign(self.colonyname, timeout, self.prvkey)
+            except (TimeoutError_, NotLeaderError):
+                return False
+            sp.set(processid=pd["processid"])
         process = Process.from_dict(pd)
-        self._execute(process)
+        with tracing.span("colony.executor.process", processid=process.processid,
+                          funcname=process.spec.funcname):
+            self._execute(process)
         return True
 
     def _execute(self, process: Process) -> None:
@@ -199,7 +204,9 @@ class ExecutorBase:
             msgid=new_id() if self.client.idempotency else "",
             counted=counted,
         )
-        if not self._try_deliver(pc):
+        with tracing.span("colony.executor.close", successful=successful):
+            delivered = self._try_deliver(pc)
+        if not delivered:
             with self._pending_lock:
                 self._pending.append(pc)
 

@@ -18,6 +18,7 @@ from __future__ import annotations
 import secrets
 from typing import Any, Callable
 
+from . import tracing
 from .database import Database
 from .errors import NotFoundError, ValidationError
 from .process import now_ns
@@ -31,7 +32,6 @@ class GeneratorExtension:
         self.server = server
         self.db: Database = server.db
         server.extensions.append(self)
-        self.triggered = 0
 
     def handlers(self) -> dict[str, Callable[[str, dict], Any]]:
         return {
@@ -118,26 +118,30 @@ class GeneratorExtension:
                 and ts - entry["firstpack"] > entry["timeout"] * 1e9
             )
             if pending >= entry["queuesize"] or timed_out:
-                self._fire(entry, ts)
+                self._fire(entry, ts, "full" if pending >= entry["queuesize"] else "timeout")
                 fired += 1
         return fired
 
-    def _fire(self, entry: dict, ts: int) -> None:
+    def _fire(self, entry: dict, ts: int, reason: str) -> None:
         gid = entry["generatorid"]
-        packs = self.db.kv_take_all(PACKS_TABLE, gid)
-        if not packs:
-            return
-        args = [p["arg"] for p in packs]
-        wf = WorkflowSpec.from_dict(entry["workflow"])
-        # Packed args are delivered to the DAG roots via kwargs.
-        for s in wf.specs:
-            if not s.conditions.dependencies:
-                s.kwargs = dict(s.kwargs)
-                s.kwargs["packed_args"] = args
-        self.server.submit_workflow_processes(wf)
-        entry = dict(entry)
-        entry["firstpack"] = 0
-        entry["runs"] = entry.get("runs", 0) + 1
-        self.db.generator_put(entry)
-        self.server._notify_queue()
-        self.triggered += 1
+        with tracing.span("colony.generator.fire", reason=reason) as sp:
+            packs = self.db.kv_take_all(PACKS_TABLE, gid)
+            if not packs:
+                return
+            args = [p["arg"] for p in packs]
+            wf = WorkflowSpec.from_dict(entry["workflow"])
+            # Packed args are delivered to the DAG roots via kwargs.
+            for s in wf.specs:
+                if not s.conditions.dependencies:
+                    s.kwargs = dict(s.kwargs)
+                    s.kwargs["packed_args"] = args
+            procs = self.server.submit_workflow_processes(wf)
+            entry = dict(entry)
+            entry["firstpack"] = 0
+            entry["runs"] = entry.get("runs", 0) + 1
+            self.db.generator_put(entry)
+            self.server._notify_queue()
+            holds = [(ts - p["ts"]) / 1e9 for p in packs]
+            sp.set(packs=len(packs), processid=procs[0].processid, oldest_hold_s=max(holds),
+                   mean_hold_s=sum(holds) / len(holds))
+        tracing.count(f"generator.fires.{reason}")

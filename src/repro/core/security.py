@@ -12,8 +12,10 @@ three-role model: server owner, colony owner, executor/user member.
 from __future__ import annotations
 
 import json
+import time
 from typing import Any
 
+from . import tracing
 from .crypto import Crypto
 from .errors import AuthError
 
@@ -30,7 +32,12 @@ def sign_envelope(
     (tampering with msgid breaks signature recovery — see ROBUSTNESS.md).
     Envelopes without a msgid sign exactly as before (back-compat)."""
     body = canonical(payload)
-    sig = Crypto.sign(payloadtype + body + (msgid or ""), prvkey)
+    with tracing.span("colony.rpc.sign", payloadtype=payloadtype) as sp:
+        t = time.thread_time()
+        sig = Crypto.sign(payloadtype + body + (msgid or ""), prvkey)
+        cpu_s = time.thread_time() - t
+        sp.set(cpu_s=cpu_s)
+    tracing.count(f"rpc.sign.{payloadtype}", seconds=cpu_s)
     env = {"payloadtype": payloadtype, "payload": body, "signature": sig}
     if msgid:
         env["msgid"] = msgid
@@ -63,8 +70,14 @@ def open_envelope(
     sig = env.get("signature", "")
     if not sig:
         raise AuthError("missing signature")
-    try:
-        identity = Crypto.recover(ptype + body + env.get("msgid", ""), sig)
-    except (ValueError, AssertionError) as e:
-        raise AuthError(f"signature recovery failed: {e}") from e
+    with tracing.span("colony.rpc.verify", payloadtype=ptype) as sp:
+        t = time.thread_time()
+        try:
+            identity = Crypto.recover(ptype + body + env.get("msgid", ""), sig)
+        except (ValueError, AssertionError) as e:
+            raise AuthError(f"signature recovery failed: {e}") from e
+        finally:
+            cpu_s = time.thread_time() - t
+            sp.set(cpu_s=cpu_s)
+            tracing.count(f"rpc.verify.{ptype}", seconds=cpu_s)
     return identity, ptype, payload

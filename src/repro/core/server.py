@@ -39,7 +39,7 @@ from ..analysis.authtrack import requires_auth
 from ..analysis.contracts import no_locks_held
 from ..analysis.locktrack import make_lock
 from ..runtime import faults
-from . import blobstore, idempotency
+from . import blobstore, idempotency, tracing
 from .database import Database, MemoryDatabase
 from .errors import (
     AuthError,
@@ -156,6 +156,10 @@ class ColoniesServer:
         path exists only for in-process benchmark/test harnesses, never
         for envelopes that crossed a trust boundary (paper §3.4.6).
         """
+        with tracing.span("colony.server.handle", payloadtype=envelope.get("payloadtype", "")):
+            return self._dispatch(envelope, external)
+
+    def _dispatch(self, envelope: dict, external: bool) -> dict:
         try:
             verify = self.verify_signatures or external
             identity, ptype, payload = open_envelope(
@@ -410,7 +414,7 @@ class ColoniesServer:
             # HA replicas wake periodically to notice lost leadership;
             # standalone servers sleep until notified (or timeout).
             tick = self.HA_LEADER_RECHECK_S if self._ha else remaining
-            with sig.cv:
+            with tracing.span("colony.broker.assign_wait"), sig.cv:
                 if sig.version == version:  # nothing arrived since we probed
                     sig.cv.wait(timeout=min(remaining, tick))
         return None
@@ -697,6 +701,9 @@ class ColoniesServer:
         # process (broker + executors share one process in this repro,
         # exactly like the InProc transport).
         stats["blob"] = blobstore.aggregate_stats()
+        # The process's own counters (signatures, generator fires, the
+        # engine's batches and compiles): see PERF.md, "Tracing".
+        stats["trace"] = tracing.counters()
         return stats
 
     # -- failsafe (paper §3.4) --------------------------------------------------

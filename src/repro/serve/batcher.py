@@ -18,6 +18,7 @@ from typing import Any
 
 import numpy as np
 
+from ..core import tracing
 from ..core.client import Colonies
 from ..core.errors import NotFoundError, TimeoutError_
 from ..core.fs import CFSClient
@@ -68,21 +69,23 @@ def make_batch_handler(engine, cfs: CFSClient, colony: str):
         requests = kwargs.get("packed_args", [])
         if not requests:
             return [0]
-        max_new = max(int(r.get("max_new_tokens", 8)) for r in requests)
-        longest = max(len(r["prompt"]) for r in requests)
-        vocab_pad = 0
-        prompts = np.full((len(requests), longest), vocab_pad, np.int32)
-        for i, r in enumerate(requests):
-            p = r["prompt"]
-            prompts[i, longest - len(p):] = p  # right-align
+        with tracing.span("colony.batch.build", requests=len(requests)):
+            max_new = max(int(r.get("max_new_tokens", 8)) for r in requests)
+            longest = max(len(r["prompt"]) for r in requests)
+            vocab_pad = 0
+            prompts = np.full((len(requests), longest), vocab_pad, np.int32)
+            for i, r in enumerate(requests):
+                p = r["prompt"]
+                prompts[i, longest - len(p):] = p  # right-align
         out = engine.generate(prompts, max_new_tokens=max_new)
-        for i, r in enumerate(requests):
-            cfs.upload_bytes(
-                colony,
-                RESULTS_LABEL,
-                f"{r['request_id']}.json",
-                json.dumps({"tokens": out[i].tolist()}).encode(),
-            )
+        with tracing.span("colony.batch.upload", files=len(requests)):
+            for i, r in enumerate(requests):
+                cfs.upload_bytes(
+                    colony,
+                    RESULTS_LABEL,
+                    f"{r['request_id']}.json",
+                    json.dumps({"tokens": out[i].tolist()}).encode(),
+                )
         return [len(requests)]
 
     return generate_batch

@@ -16,7 +16,39 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
+from ..core import tracing
 from ..models.model import decode_step, forward, pad_cache, prefill
+
+# Every colony span is also an annotation in the profiler's trace, and
+# spans are recorded whenever the profiler captures.
+tracing.install(jax.profiler.TraceAnnotation, jax.profiler.TraceAnnotation.is_enabled)
+
+# The phases of building one program, as jax.monitoring reports them.
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+
+
+def _on_compile_phase(event: str, secs: float, **kw) -> None:
+    """While tracing is on: ``jax.compiles.<function>`` counts the
+    programs this process builds, the engine's and any other's (compiled,
+    or loaded from the persistent cache), and the seconds of all three
+    phases; each phase is also a span."""
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None or not tracing.is_on():
+        return
+    fun = str(kw.get("fun_name", "?"))
+    if fun.startswith("jit(") and fun.endswith(")"):
+        fun = fun[4:-1]
+    tracing.count(f"jax.compiles.{fun}", int(phase == "compile"), secs)
+    now = time.time()
+    tracing.record("colony.jax.compile", now - secs, now, fun=fun, phase=phase)
+
+
+# One listener for the whole process, registered when the engine is first imported.
+jax.monitoring.register_event_duration_secs_listener(_on_compile_phase)
 
 
 def make_serve_step(cfg: ModelConfig):
@@ -54,7 +86,15 @@ class ServeEngine:
         self._prefill = jax.jit(make_prefill(cfg, max_len))
         self._decode = jax.jit(make_serve_step(cfg))
         self._sample = jax.jit(sample_token, static_argnums=(2,))
-        self.stats = {"requests": 0, "tokens": 0, "batches": 0, "seconds": 0.0}
+        self.counters = tracing.Counters("engine.")
+
+    @property
+    def stats(self) -> dict:
+        """Requests, tokens and batches generated, and generate's seconds."""
+        batches, seconds = self.counters.get("batches")
+        return {"requests": self.counters.get("requests")[0],
+                "tokens": self.counters.get("tokens")[0], "batches": batches,
+                "seconds": seconds}
 
     def warmup(self, batch: int, prompt_len: int, max_new_tokens: int) -> dict[str, float]:
         """Compile everything one batch shape needs, so that serving it
@@ -82,11 +122,11 @@ class ServeEngine:
         b, s = tokens.shape
         assert s + max_new_tokens <= self.max_len, "increase max_len"
         t0 = time.perf_counter()
-        out = self._generate(tokens, max_new_tokens, temperature, seed, extras)
-        self.stats["requests"] += b
-        self.stats["tokens"] += b * max_new_tokens
-        self.stats["batches"] += 1
-        self.stats["seconds"] += time.perf_counter() - t0
+        with tracing.span("colony.engine.generate", batch=b, seq=s, new=max_new_tokens):
+            out = self._generate(tokens, max_new_tokens, temperature, seed, extras)
+        self.counters.count("requests", b)
+        self.counters.count("tokens", b * max_new_tokens)
+        self.counters.count("batches", 1, time.perf_counter() - t0)
         return out
 
     def _generate(self, tokens: np.ndarray, max_new_tokens: int, temperature: float = 0.0,
@@ -94,16 +134,22 @@ class ServeEngine:
         batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
         if extras:
             batch.update({k: jnp.asarray(v) for k, v in extras.items()})
-        logits, cache = self._prefill(self.params, batch)
-        rng = jax.random.key(seed)
-        out = []
-        tok = self._sample(logits, rng, temperature)
-        out.append(tok)
-        pos = tokens.shape[1]
-        for i in range(max_new_tokens - 1):
-            rng, sub = jax.random.split(rng)
-            logits, cache = self._decode(self.params, tok, cache, jnp.int32(pos))
-            tok = self._sample(logits, sub, temperature)
+        with tracing.span("colony.engine.prefill"):
+            logits, cache = self._prefill(self.params, batch)
+            rng = jax.random.key(seed)
+            out = []
+            tok = self._sample(logits, rng, temperature)
             out.append(tok)
-            pos += 1
-        return np.asarray(jnp.concatenate(out, axis=1))
+        pos = tokens.shape[1]
+        # The loop only dispatches; the device catches up during collect.
+        with tracing.span("colony.engine.decode", steps=max_new_tokens - 1):
+            for i in range(max_new_tokens - 1):
+                rng, sub = jax.random.split(rng)
+                logits, cache = self._decode(self.params, tok, cache, jnp.int32(pos))
+                tok = self._sample(logits, sub, temperature)
+                out.append(tok)
+                pos += 1
+        # The eager concatenate (a program per batch size and length) and
+        # the fetch to the host, which waits for the device.
+        with tracing.span("colony.engine.collect"):
+            return np.asarray(jnp.concatenate(out, axis=1))
