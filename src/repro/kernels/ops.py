@@ -12,6 +12,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .decode_attention import decode_attention_stacked
 from .flash_attention import flash_attention_bhsd
 from .mamba_scan import mamba_chunk_scan_b
 from .rwkv6 import rwkv6_chunked_bh
@@ -42,6 +43,27 @@ def flash_attention(
         block_q=block_q, block_k=block_k, interpret=_interpret(),
     )
     return jnp.moveaxis(out.reshape(b, h, s, d), 1, 2)
+
+
+@jax.jit
+def decode_attention(
+    q: jnp.ndarray,  # (B, KV, G, D)
+    k_new: jnp.ndarray,  # (B, KV, D)
+    v_new: jnp.ndarray,
+    k: jnp.ndarray,  # (L, B, KV, D, S) stacked cache, positions minor
+    v: jnp.ndarray,
+    layer: jnp.ndarray,  # int32 scalar
+    pos: jnp.ndarray,  # int32 scalar
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Slot ``pos`` of layer ``layer`` takes the new K/V, and one token per
+    row attends over that layer; returns (attention, k, v). The branch is
+    picked when the program is lowered: Mosaic for a TPU (also one that is
+    only described), the interpreter for the CPU."""
+    return jax.lax.platform_dependent(
+        q, k_new, v_new, k, v, layer, pos,
+        cpu=partial(decode_attention_stacked, interpret=True),
+        tpu=decode_attention_stacked,
+    )
 
 
 @partial(jax.jit, static_argnames=("chunk",))

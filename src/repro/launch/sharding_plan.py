@@ -118,12 +118,12 @@ def cache_pspecs(cfg: ModelConfig, cache_tree: Any, mesh: Mesh) -> Any:
 
     def leaf_spec(name: str, s) -> P:
         shp = s.shape
-        if name in ("k", "v"):  # (L, B, S, KV, HD)
-            _, b, seq, kv, hd = shp
+        if name in ("k", "v"):  # (L, B, KV, HD, S): positions minor
+            _, b, kv, hd, seq = shp
             if kv % model_n == 0:
-                return P(None, bspec(b), None, "model", None)
-            if seq % model_n == 0:  # sequence-parallel KV cache
                 return P(None, bspec(b), "model", None, None)
+            if seq % model_n == 0:  # sequence-parallel KV cache
+                return P(None, bspec(b), None, None, "model")
             return P(None, bspec(b))
         if name in ("xk", "xv"):  # (L, B, M, KV, HD)
             _, b, m, kv, hd = shp

@@ -1,13 +1,15 @@
 """GQA / sliding-window / cross attention with KV caching.
 
-Pure-jnp paths are the defaults (they lower on any backend, including the
-512-device dry-run); the Pallas flash kernel (kernels/flash_attention.py)
-is selected with ``cfg.use_pallas`` for TPU execution.
+Pure-jnp paths lower on any backend, including the 512-device dry-run.
+Prefill selects the Pallas flash kernel (kernels/flash_attention.py) with
+``cfg.use_pallas``; decode runs the Pallas decode kernel
+(kernels/decode_attention.py) wherever ``decode_kernel_serves`` observes
+that it can.
 
 Parameter spec + three entry points per block:
   * ``attn_fwd``        — full-sequence training/prefill forward
-  * ``attn_decode``     — single-token decode against a cache
-  * ``init_attn_cache`` — cache pytree (ring buffer when SWA bounds it)
+  * ``attn_decode``     — single-token decode against the stacked cache
+  * ``init_attn_cache`` — cache pytree, positions minor (ring buffer when SWA bounds it)
 """
 
 from __future__ import annotations
@@ -18,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..kernels.decode_attention import LANES
 from .layers import apply_rope, causal_mask
 from .sharding import ParamLeaf
 
@@ -185,77 +188,116 @@ def cross_attn_fwd(
 
 
 # ---------------------------------------------------------------------------
-# KV cache (contiguous, or ring buffer under sliding-window attention)
+# KV cache: (B, KV, head_dim, slots), positions minor — contiguous, or a ring
+# buffer under sliding-window attention. Stacked per layer it is the layout
+# the decode kernel reads (kernels/decode_attention.py).
 # ---------------------------------------------------------------------------
 
 
 def cache_len(cfg: ModelConfig, max_len: int) -> int:
-    """SWA bounds the live KV window — the decode cache is a ring buffer."""
+    """SWA bounds the live KV window — the decode cache is a ring buffer.
+    A contiguous cache rounds its slots up to whole lane tiles: the decode
+    kernel writes by tiles, and XLA stores only such slot counts
+    position-minor too."""
     if cfg.sliding_window > 0:
         return min(cfg.sliding_window, max_len)
-    return max_len
+    return -(-max_len // LANES) * LANES
+
+
+def _cache_shape(cfg: ModelConfig, batch: int, max_len: int) -> tuple[int, ...]:
+    return (batch, cfg.num_kv_heads, cfg.head_dim, cache_len(cfg, max_len))
 
 
 def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
-    ln = cache_len(cfg, max_len)
-    kv, hd = cfg.num_kv_heads, cfg.head_dim
-    shape = (batch, ln, kv, hd)
-    return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
-    }
+    shape = _cache_shape(cfg, batch, max_len)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def abstract_attn_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
-    ln = cache_len(cfg, max_len)
-    kv, hd = cfg.num_kv_heads, cfg.head_dim
-    shape = (batch, ln, kv, hd)
-    return {
-        "k": jax.ShapeDtypeStruct(shape, dtype),
-        "v": jax.ShapeDtypeStruct(shape, dtype),
-    }
+    shape = _cache_shape(cfg, batch, max_len)
+    return {"k": jax.ShapeDtypeStruct(shape, dtype), "v": jax.ShapeDtypeStruct(shape, dtype)}
 
 
 def make_cache_from_prefill(k: jnp.ndarray, v: jnp.ndarray, cfg: ModelConfig) -> dict:
-    """Prefill K/V -> decode cache. Under SWA, keep the last ``window``
-    positions and rotate them into ring order (slot = position % window)
-    so subsequent decode writes land in the right slots."""
+    """Prefill K/V (B, S, KV, D) -> decode cache (B, KV, D, slots). Under
+    SWA, keep the last ``window`` positions and rotate them into ring order
+    (slot = position % window) so subsequent decode writes land in the
+    right slots; a contiguous cache pads to whole lane tiles."""
     s = k.shape[1]
+    k, v = jnp.moveaxis(k, 1, -1), jnp.moveaxis(v, 1, -1)
     w = cfg.sliding_window
     if w > 0 and s > w:
-        k = jnp.roll(k[:, -w:], shift=s % w, axis=1)
-        v = jnp.roll(v[:, -w:], shift=s % w, axis=1)
+        k = jnp.roll(k[..., -w:], shift=s % w, axis=-1)
+        v = jnp.roll(v[..., -w:], shift=s % w, axis=-1)
+    elif w == 0:  # whole lane tiles
+        pad = [(0, 0)] * (k.ndim - 1) + [(0, cache_len(cfg, s) - s)]
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)
     return {"k": k, "v": v}
+
+
+def decode_kernel_serves(cfg: ModelConfig) -> bool:
+    """Whether ``attn_decode`` runs the Pallas decode kernel: a contiguous
+    cache in a program on one device, on a TPU or interpreted on the CPU.
+    Ring buffers, programs partitioned over a mesh and other backends keep
+    the XLA path."""
+    from .sharding import current_activation_mesh
+
+    return (cfg.sliding_window == 0 and current_activation_mesh() is None
+            and jax.default_backend() in ("tpu", "cpu"))
+
+
+def _attend_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                valid: jnp.ndarray) -> jnp.ndarray:
+    """q (B, KV, G, D) over one layer's k, v (B, KV, D, T) where ``valid``."""
+    scores = jnp.einsum("bkgd,bkdt->bkgt", q, k).astype(jnp.float32) * q.shape[-1] ** -0.5
+    scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bkgt,bkdt->bkgd", probs.astype(v.dtype), v)
 
 
 def attn_decode(
     params: dict,
     x_t: jnp.ndarray,  # (B, 1, d_model)
-    cache: dict,
+    cache: dict,  # k, v: (layers, B, KV, D, slots), every layer of the stack
+    layer: jnp.ndarray,  # scalar int32 — this layer's index in the stack
     pos: jnp.ndarray,  # scalar int32 — absolute position of this token
     cfg: ModelConfig,
 ) -> tuple[jnp.ndarray, dict]:
+    """One token against its layer of the stacked cache. The new K/V
+    column is written into slot ``pos`` of that layer (the kernel writes the
+    lane tile holding it; XLA a one-column update); the stack is returned
+    whole."""
     q, k_t, v_t = _project_qkv(params, x_t)
     pos_arr = jnp.reshape(pos, (1,))
     if cfg.use_rope:
         q = apply_rope(q, pos_arr, cfg.rope_theta)
         k_t = apply_rope(k_t, pos_arr, cfg.rope_theta)
 
-    ln = cache["k"].shape[1]
+    ln = cache["k"].shape[-1]
     if cfg.sliding_window > 0:
         slot = pos % ln  # ring buffer — O(window) memory at any context length
     else:
         slot = jnp.minimum(pos, ln - 1)
-    k = jax.lax.dynamic_update_slice(cache["k"], k_t.astype(cache["k"].dtype), (0, slot, 0, 0))
-    v = jax.lax.dynamic_update_slice(cache["v"], v_t.astype(cache["v"].dtype), (0, slot, 0, 0))
+    b, _, h, d = q.shape
+    kv = cache["k"].shape[2]
+    qg = q.reshape(b, kv, h // kv, d)
+    if decode_kernel_serves(cfg):  # slots 0..slot are this token's context
+        from ..kernels.ops import decode_attention
 
-    # Validity: ring slots written so far; contiguous cache positions <= pos.
-    idx = jnp.arange(ln)
-    if cfg.sliding_window > 0:
-        valid = idx < jnp.minimum(pos + 1, ln)  # ring fully valid once wrapped
+        out, k, v = decode_attention(qg, k_t[:, 0], v_t[:, 0], cache["k"], cache["v"], layer, slot)
     else:
-        valid = idx <= pos
-    mask = valid[None, None, None, None, :]  # (1,1,1,1,Skv) -> broadcast
-    out = gqa_scores_softmax_out(q, k, v, mask)
-    y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
+        at = (layer, 0, 0, 0, slot)
+        k = jax.lax.dynamic_update_slice(
+            cache["k"], jnp.moveaxis(k_t, 1, -1)[None].astype(cache["k"].dtype), at)
+        v = jax.lax.dynamic_update_slice(
+            cache["v"], jnp.moveaxis(v_t, 1, -1)[None].astype(cache["v"].dtype), at)
+        # Validity: ring slots written so far; contiguous cache positions <= pos.
+        idx = jnp.arange(ln)
+        if cfg.sliding_window > 0:
+            valid = idx < jnp.minimum(pos + 1, ln)  # ring fully valid once wrapped
+        else:
+            valid = idx <= pos
+        out = _attend_xla(qg, jax.lax.dynamic_index_in_dim(k, layer, keepdims=False),
+                          jax.lax.dynamic_index_in_dim(v, layer, keepdims=False), valid)
+    y = jnp.einsum("bshk,hkd->bsd", out.reshape(b, 1, h, d), params["wo"])
     return y, {"k": k, "v": v}

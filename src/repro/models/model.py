@@ -481,11 +481,15 @@ def abstract_cache(cfg: ModelConfig, batch: int, max_len: int, mem_len: int = 0)
 
 
 def _block_decode(bdef: BlockDef, bparams: dict, x: jnp.ndarray, cache: dict,
-                  pos: jnp.ndarray, cfg: ModelConfig, memory: jnp.ndarray | None):
+                  layer: jnp.ndarray, pos: jnp.ndarray, cfg: ModelConfig,
+                  memory: jnp.ndarray | None):
+    """``cache`` holds this layer's slices, except self-attention K/V: the
+    whole stack, of which ``layer`` is this block's."""
     new_cache = dict(cache)
     h = apply_norm(x, bparams["norm1"], cfg.norm, cfg.norm_eps)
     if bdef.mixer in ("attn", "attn_x"):
-        out, upd = attn.attn_decode(bparams["mixer"], h, {"k": cache["k"], "v": cache["v"]}, pos, cfg)
+        out, upd = attn.attn_decode(
+            bparams["mixer"], h, {"k": cache["k"], "v": cache["v"]}, layer, pos, cfg)
         new_cache.update(upd)
     elif bdef.mixer == "xattn":
         out = _xattn_decode(bparams["mixer"], h, cache)
@@ -534,23 +538,51 @@ def _xattn_decode(params: dict, h: jnp.ndarray, cache: dict) -> jnp.ndarray:
 def _decode_stack(layout: Layout, groups_params: dict, layer_caches: dict,
                   x: jnp.ndarray, pos: jnp.ndarray, cfg: ModelConfig,
                   memory: jnp.ndarray | None):
-    def body(x, xs):
-        gparams, gcache = xs
-        new_caches = {}
-        for i, bdef in enumerate(layout.group):
-            x, nc = _block_decode(bdef, gparams[f"b{i}"], x, gcache[f"b{i}"], pos, cfg, memory)
-            new_caches[f"b{i}"] = nc
-        return x, new_caches
+    """Decode through the groups. Self-attention K/V ride in the carry as
+    whole stacks, each layer writing its new column in place and reading
+    its own layer where it lies; every other cache leaf is sliced per group
+    and stacked back."""
+    kv_keys = ("k", "v")
+    carried = {n: {k: c[k] for k in kv_keys} for n, c in layer_caches.items() if "k" in c}
+    sliced = {n: {k: leaf for k, leaf in c.items() if n not in carried or k not in kv_keys}
+              for n, c in layer_caches.items()}
 
+    def body(carry, xs):
+        x, kv = carry
+        gparams, gcache, layer = xs
+        kv, new_caches = dict(kv), {}
+        for i, bdef in enumerate(layout.group):
+            name = f"b{i}"
+            x, nc = _block_decode(bdef, gparams[name], x, {**gcache[name], **kv.get(name, {})},
+                                  layer, pos, cfg, memory)
+            if name in kv:
+                kv[name] = {k: nc.pop(k) for k in kv_keys}
+            new_caches[name] = nc
+        return (x, kv), new_caches
+
+    # The depth as the parameters give it, which is what the scan walks.
+    depth = jax.tree.leaves(groups_params)[0].shape[0]
+    layers = jnp.arange(depth, dtype=jnp.int32)
     if cfg.scan_layers and layout.num_groups > 1:
-        return jax.lax.scan(body, x, (groups_params, layer_caches))
-    outs = []
-    for g in range(layout.num_groups):
-        gparams = jax.tree.map(lambda p: p[g], groups_params)
-        gcache = jax.tree.map(lambda c: c[g], layer_caches)
-        x, nc = body(x, (gparams, gcache))
-        outs.append(nc)
-    return x, jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+        (x, carried), sliced = jax.lax.scan(body, (x, carried), (groups_params, sliced, layers))
+    else:
+        outs, carry = [], (x, carried)
+        for g in range(depth):
+            gparams, gcache = jax.tree.map(lambda p: p[g], (groups_params, sliced))
+            carry, nc = body(carry, (gparams, gcache, layers[g]))
+            outs.append(nc)
+        (x, carried) = carry
+        sliced = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    return x, {n: {**sliced[n], **carried.get(n, {})} for n in layer_caches}
+
+
+def decode_uses_kernel(cfg: ModelConfig) -> bool:
+    """Whether ``decode_step`` runs the Pallas decode kernel in any layer."""
+    layouts = [decoder_layout(cfg) if not cfg.is_encdec else decoder_with_cross_layout(cfg)]
+    if cfg.dense_prefix_layers > 0:
+        layouts.append(prefix_layout(cfg))
+    return attn.decode_kernel_serves(cfg) and any(
+        b.mixer in ("attn", "attn_x") for lo in layouts for b in lo.group)
 
 
 def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -574,46 +606,35 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     return logits, new_cache
 
 
-_SEQ_CACHE_KEYS = ("k", "v", "c_kv", "k_rope")  # leaves with a seq axis at dim 2
-
-
 def pad_cache(cache: dict, cfg: ModelConfig, max_len: int) -> dict:
     """Grow sequence-indexed cache leaves to ``max_len`` decode slots.
 
-    Leaves are stacked (groups, B, S, ...); state caches (mamba/rwkv) and
-    cross-attention memories are untouched. Ring buffers (SWA) are already
-    bounded by the window and never grow.
+    Leaves are stacked per group: self-attention K/V (groups, B, KV, D, S)
+    grow on their last axis to ``attn.cache_len`` slots, the MLA latent and
+    rope keys (groups, B, S, ...) on axis 2 to ``max_len``. State caches
+    (mamba/rwkv) and cross-attention memories are untouched. Ring buffers
+    (SWA) are already bounded by the window and never grow.
     """
-    target = attn.cache_len(cfg, max_len)
+    seq_axis = {"k": -1, "v": -1, "c_kv": 2, "k_rope": 2}
+    target = {"k": attn.cache_len(cfg, max_len), "v": attn.cache_len(cfg, max_len),
+              "c_kv": max_len, "k_rope": max_len}
 
-    def fix(path_leaf):
-        def walk(tree):
-            if not isinstance(tree, dict):
-                return tree
-            out = {}
-            for k, val in tree.items():
-                if isinstance(val, dict):
-                    out[k] = walk(val)
-                elif k in _SEQ_CACHE_KEYS and hasattr(val, "ndim") and val.ndim >= 3:
-                    s = val.shape[2]
-                    tgt = target if k in ("k", "v") else max_len
-                    if k in ("c_kv", "k_rope"):
-                        tgt = max_len
-                    if s < tgt:
-                        pad = [(0, 0)] * val.ndim
-                        pad[2] = (0, tgt - s)
-                        val = jnp.pad(val, pad)
-                    out[k] = val
-                else:
-                    out[k] = val
-            return out
-
-        return walk(path_leaf)
+    def walk(tree):
+        out = {}
+        for k, val in tree.items():
+            if isinstance(val, dict):
+                val = walk(val)
+            elif k in seq_axis and val.shape[seq_axis[k]] < target[k]:
+                pad = [(0, 0)] * val.ndim
+                pad[seq_axis[k]] = (0, target[k] - val.shape[seq_axis[k]])
+                val = jnp.pad(val, pad)
+            out[k] = val
+        return out
 
     new = dict(cache)
-    new["layers"] = fix(cache["layers"])
+    new["layers"] = walk(cache["layers"])
     if "prefix_layers" in cache:
-        new["prefix_layers"] = fix(cache["prefix_layers"])
+        new["prefix_layers"] = walk(cache["prefix_layers"])
     return new
 
 

@@ -17,7 +17,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..core import tracing
-from ..models.model import decode_step, forward, pad_cache, prefill
+from ..models.model import decode_step, decode_uses_kernel, forward, pad_cache, prefill
 
 # Every colony span is also an annotation in the profiler's trace, and
 # spans are recorded whenever the profiler captures.
@@ -67,6 +67,14 @@ def make_prefill(cfg: ModelConfig, max_len: int | None = None):
     return prefill_fn
 
 
+def donated_cache() -> tuple[int, ...]:
+    """``serve_step``'s donated arguments. On the chip the cache is donated,
+    so each step writes its new K/V where they lie instead of copying the
+    whole cache, and holds one cache fewer. On the CPU, where tests drive
+    the engine, a caller may keep using a cache it passed in."""
+    return (2,) if jax.default_backend() == "tpu" else ()
+
+
 def sample_token(logits: jnp.ndarray, rng: jax.Array, temperature: float) -> jnp.ndarray:
     """logits: (B,1,V) -> (B,1) int32."""
     if temperature <= 0.0:
@@ -84,17 +92,21 @@ class ServeEngine:
         self.params = params
         self.max_len = max_len
         self._prefill = jax.jit(make_prefill(cfg, max_len))
-        self._decode = jax.jit(make_serve_step(cfg))
+        self._decode = jax.jit(make_serve_step(cfg), donate_argnums=donated_cache())
         self._sample = jax.jit(sample_token, static_argnums=(2,))
+        self.decode_path = "kernel" if decode_uses_kernel(cfg) else "xla"
         self.counters = tracing.Counters("engine.")
 
     @property
     def stats(self) -> dict:
-        """Requests, tokens and batches generated, and generate's seconds."""
+        """Requests, tokens and batches generated, generate's seconds, and
+        the decode calls made by the Pallas kernel's path and by XLA's."""
         batches, seconds = self.counters.get("batches")
         return {"requests": self.counters.get("requests")[0],
                 "tokens": self.counters.get("tokens")[0], "batches": batches,
-                "seconds": seconds}
+                "seconds": seconds,
+                "decode_kernel": self.counters.get("decode.kernel")[0],
+                "decode_xla": self.counters.get("decode.xla")[0]}
 
     def warmup(self, batch: int, prompt_len: int, max_new_tokens: int) -> dict[str, float]:
         """Compile everything one batch shape needs, so that serving it
@@ -127,6 +139,7 @@ class ServeEngine:
         self.counters.count("requests", b)
         self.counters.count("tokens", b * max_new_tokens)
         self.counters.count("batches", 1, time.perf_counter() - t0)
+        self.counters.count(f"decode.{self.decode_path}", max(max_new_tokens - 1, 0))
         return out
 
     def _generate(self, tokens: np.ndarray, max_new_tokens: int, temperature: float = 0.0,

@@ -10,7 +10,8 @@ try:  # optional dependency — only the property test below needs it
 except ImportError:
     given = None
 
-from repro.kernels.ops import flash_attention, mamba_chunk_scan, rwkv6_chunked
+from repro.kernels.decode_attention import decode_attention_stacked
+from repro.kernels.ops import decode_attention, flash_attention, mamba_chunk_scan, rwkv6_chunked
 from repro.kernels.ref import flash_attention_ref, mamba_scan_ref, rwkv6_ref
 
 
@@ -176,6 +177,80 @@ def test_mamba_nonzero_state_carry():
     ry, rh = mamba_scan_ref(dt, bm, cm, a, x, h0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ry), atol=1e-4, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(hf), np.asarray(rh), atol=1e-4, rtol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# decode attention over the stacked, position-minor cache
+# ---------------------------------------------------------------------------
+
+DECODE_SLOTS = 384  # three 128-lane blocks
+
+
+@pytest.mark.parametrize("pos", [0, 127, 128, DECODE_SLOTS - 1])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("group,head_dim", [(1, 80), (4, 128)])  # stablelm MHA; granite GQA
+def test_decode_attention_matches_gqa(group, head_dim, dtype, pos):
+    """attn_decode writes slot ``pos`` of its layer and nothing else; the
+    kernel (one block, and 128-position blocks) writes the same cache and
+    matches ``gqa_scores_softmax_out`` on the same K/V in the (B, S, KV, D)
+    layout."""
+    from repro.configs import get_config
+    from repro.models.attention import (
+        _project_qkv, apply_rope, attn_decode, attn_spec, gqa_scores_softmax_out)
+    from repro.models.sharding import init_params
+
+    layers, b, kv, layer = 3, 2, 2, 1
+    cfg = get_config("stablelm-3b", "smoke").copy(
+        num_heads=kv * group, num_kv_heads=kv, head_dim=head_dim, d_model=64)
+    params = init_params(jax.random.key(0), attn_spec(cfg), dtype)
+    ks = jax.random.split(jax.random.key(pos + group), 3)
+    x = jax.random.normal(ks[0], (b, 1, cfg.d_model), dtype)
+    shape = (layers, b, kv, head_dim, DECODE_SLOTS)
+    cache = {"k": jax.random.normal(ks[1], shape, dtype), "v": jax.random.normal(ks[2], shape, dtype)}
+
+    y, new = attn_decode(params, x, cache, jnp.int32(layer), jnp.int32(pos), cfg)
+    for name in ("k", "v"):
+        changed = np.argwhere(np.asarray(new[name] != cache[name]))
+        assert set(map(tuple, changed[:, [0, 4]])) <= {(layer, pos)}, name
+        np.testing.assert_array_equal(np.delete(np.asarray(new[name]), pos, axis=4),
+                                      np.delete(np.asarray(cache[name]), pos, axis=4))
+
+    q, _, _ = _project_qkv(params, x)
+    q = apply_rope(q, jnp.reshape(pos, (1,)), cfg.rope_theta)
+    k_bskd = jnp.moveaxis(new["k"][layer], -1, 1)  # (B, S, KV, D), today's layout
+    v_bskd = jnp.moveaxis(new["v"][layer], -1, 1)
+    mask = (jnp.arange(DECODE_SLOTS) <= pos)[None, None, None, None, :]
+    ref = gqa_scores_softmax_out(
+        q.astype(jnp.float32), k_bskd.astype(jnp.float32), v_bskd.astype(jnp.float32), mask)
+    tol = dict(atol=2e-5, rtol=1e-4) if dtype == jnp.float32 else dict(atol=3e-2, rtol=3e-2)
+    qg = q.reshape(b, kv, group, head_dim)
+    args = (qg, new["k"][layer, ..., pos], new["v"][layer, ..., pos], cache["k"], cache["v"],
+            jnp.int32(layer), jnp.int32(pos))
+    for out, k, v in (
+        decode_attention(*args),
+        decode_attention_stacked(*args, block_bytes=head_dim * 128 * jnp.dtype(dtype).itemsize,
+                                 interpret=True),
+    ):
+        assert out.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(k), np.asarray(new["k"]))
+        np.testing.assert_array_equal(np.asarray(v), np.asarray(new["v"]))
+        np.testing.assert_allclose(np.asarray(out.reshape(ref.shape), np.float32),
+                                   np.asarray(ref), **tol)
+    y_ref = jnp.einsum("bshk,hkd->bsd", ref, params["wo"].astype(jnp.float32))
+    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y_ref),
+                               **(tol if dtype == jnp.float32 else dict(atol=1e-1, rtol=5e-2)))
+
+
+@pytest.mark.parametrize("kv,d,s,itemsize,want", [
+    (32, 80, 384, 2, (16, 384)),  # stablelm-3b: whole positions, half the heads
+    (8, 128, 4224, 2, (1, 1408)),  # granite at 4224 slots: one head, 128-multiples
+    (8, 128, 1 << 15, 2, (1, 4096)),
+    (4, 16, 128, 4, (4, 128)),
+])
+def test_decode_block_shape(kv, d, s, itemsize, want):
+    from repro.kernels.decode_attention import block_shape
+
+    assert block_shape(kv, d, s, itemsize) == want
 
 
 # ---------------------------------------------------------------------------

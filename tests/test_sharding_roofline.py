@@ -73,18 +73,19 @@ def test_opt_pspecs_adafactor_drops_factored_axis(mesh22):
 def test_cache_pspecs_kv_heads_vs_seq_fallback(mesh22):
     # kv divisible -> heads sharded; kv indivisible -> seq sharded
     cfg = get_config("stablelm-3b", "full")
+    # (L, B, KV, HD, S): positions minor
     div = {"layers": {"b0": {
-        "k": jax.ShapeDtypeStruct((2, 4, 8, 2, 16), jnp.bfloat16),
-        "v": jax.ShapeDtypeStruct((2, 4, 8, 2, 16), jnp.bfloat16),
+        "k": jax.ShapeDtypeStruct((2, 4, 2, 16, 8), jnp.bfloat16),
+        "v": jax.ShapeDtypeStruct((2, 4, 2, 16, 8), jnp.bfloat16),
     }}, "memory": None}
     ps = cache_pspecs(cfg, div, mesh22)
-    assert tuple(ps["layers"]["b0"]["k"])[3] == "model"  # kv=2 % 2 == 0
+    assert tuple(ps["layers"]["b0"]["k"])[2] == "model"  # kv=2 % 2 == 0
     odd = {"layers": {"b0": {
-        "k": jax.ShapeDtypeStruct((2, 4, 8, 3, 16), jnp.bfloat16),
-        "v": jax.ShapeDtypeStruct((2, 4, 8, 3, 16), jnp.bfloat16),
+        "k": jax.ShapeDtypeStruct((2, 4, 3, 16, 8), jnp.bfloat16),
+        "v": jax.ShapeDtypeStruct((2, 4, 3, 16, 8), jnp.bfloat16),
     }}, "memory": None}
     ps = cache_pspecs(cfg, odd, mesh22)
-    assert tuple(ps["layers"]["b0"]["k"])[2] == "model"  # seq fallback
+    assert tuple(ps["layers"]["b0"]["k"])[4] == "model"  # seq fallback
 
 
 def test_cell_skip_policy():

@@ -8,6 +8,7 @@ run nothing. The topology is described inside a fixture, never at import.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,8 @@ from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.mamba_scan import mamba_chunk_scan_b
 from repro.kernels.rwkv6 import rwkv6_chunked_bh
 from repro.models import init_params, model_spec
+from repro.models.model import abstract_cache
+from repro.models.sharding import abstract_params
 from repro.serve.engine import make_prefill, make_serve_step
 
 HBM_BYTES = 16 * 2**30  # one TPU v5e chip
@@ -109,3 +112,43 @@ def test_stablelm_3b_serve_steps_fit_one_chip(one_chip):
         m = compiled.memory_analysis()
         used = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes
         assert 0 < used < HBM_BYTES, used
+
+
+@pytest.mark.parametrize("arch,layers,batch,max_len", [
+    ("stablelm-3b", None, 10, 384),  # chat-peak: B=10, 384 slots, MHA d=80
+    ("granite-3-8b", 10, 6, 4128),  # rag: one 10-layer stage, B=6, GQA d=128
+])
+def test_serve_step_reads_the_cache_where_it_lies(one_chip, arch, layers, batch, max_len):
+    """The engine's decode step with its cache donated: the decode kernel
+    reads each layer in place, so no copy of a layer's or the whole cache's
+    K or V is left, the output aliases the cache, and the temporaries stay
+    under one layer's K plus V."""
+    cfg = get_config(arch, "full")
+    if layers:
+        cfg = cfg.copy(num_layers=layers)
+
+    def place(tree):
+        return jax.tree.map(lambda s: _on(one_chip, s.shape, s.dtype), tree)
+
+    params = place(abstract_params(model_spec(cfg), jnp.dtype(cfg.param_dtype)))
+    cache = place(abstract_cache(cfg, batch, max_len))
+    compiled = jax.jit(make_serve_step(cfg), donate_argnums=(2,)).lower(
+        params, _on(one_chip, (batch, 1), jnp.int32), cache, _on(one_chip, (), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_serve_step,")
+    assert "tpu_custom_call" in text
+
+    kv_leaves = [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+                 if jax.tree_util.keystr(path[-1:]) in ("['k']", "['v']")]
+    stack = kv_leaves[0].shape  # (L, B, KV, D, S)
+    layer_bytes = 2 * (kv_leaves[0].size // stack[0]) * kv_leaves[0].dtype.itemsize
+    cache_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in kv_leaves)
+    banned = {tuple(sorted(stack)), tuple(sorted(stack[1:]))}
+    for dims in re.findall(r"= bf16\[([\d,]+)\]\S* copy(?:-start)?\(", text):
+        shape = tuple(sorted(int(n) for n in dims.split(",") if n != "1"))
+        assert shape not in banned, f"copy of a K/V layer or stack: bf16[{dims}]"
+
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= cache_bytes
+    assert m.temp_size_in_bytes < layer_bytes
