@@ -65,8 +65,9 @@ def sample(ex: dict, colony: list[list], host: list[dict]) -> dict:
                          if s["start"] < hi_wall and s["end"] > lo_wall]
     out["host_window"] = [lo_wall, hi_wall]
     red = trace.reduce(out)
-    run = Run(dims=None, peaks=None, seconds=0.0, t0_wall=lo_wall, end_wall=hi_wall, records=[],
-              processes=[], calls=[], compiles_in_window=0, memory_peak_bytes=None, trace=red)
+    run = Run(block=None, dims=None, peaks=None, seconds=0.0, t0_wall=lo_wall,
+              end_wall=hi_wall, records=[], processes=[], calls=[], compiles_in_window=0,
+              memory_peak_bytes=None, trace=red)
     out["expected"] = {
         "device_idle_share": 1 - red["busy_s"] / red["window_s"],
         "attribution": program_spans.attribute(out["programs"]["0"], out["colony_spans"], lo, hi),

@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from . import reference
 from .traffic import STREAM_SAMPLE, Request, rng_for
 
 
@@ -46,14 +45,14 @@ def gaps(ref_logits: np.ndarray, tokens: np.ndarray) -> np.ndarray:
     return best - chosen
 
 
-def compare(weights: dict, dims, requests: list[Request], picked: list[dict],
+def compare(block, weights: dict, dims, requests: list[Request], picked: list[dict],
             pad_to: int, rows: int, control: bool = False) -> dict:
     """Widest gap over the served tokens of ``picked`` (and, with
     ``control``, the widest gap of the tokens the fp8 control would put
-    first at the same positions). Sequences are right-padded to
-    ``pad_to`` tokens so that every run compiles the same shapes;
-    padding follows every position read, so causal attention never
-    sees it."""
+    first at the same positions), by ``block``'s reference. Sequences are
+    right-padded to ``pad_to`` tokens so that every run compiles the same
+    shapes; padding follows every position read, so causal attention
+    never sees it."""
     import jax.numpy as jnp
 
     plen = len(requests[0].prompt)
@@ -69,9 +68,9 @@ def compare(weights: dict, dims, requests: list[Request], picked: list[dict],
             seq = np.concatenate([requests[r["i"]].prompt, out[:-1]])[:pad_to]
             toks[j, : len(seq)] = seq
             served.append(out)
-        ref = np.asarray(reference.logits(weights, dims, jnp.asarray(toks), plen - 1))
-        ctl = (np.asarray(reference.logits(weights, dims, jnp.asarray(toks), plen - 1,
-                                           quant=True)) if control else None)
+        ref = np.asarray(block.logits(weights, dims, jnp.asarray(toks), plen - 1))
+        ctl = (np.asarray(block.logits(weights, dims, jnp.asarray(toks), plen - 1,
+                                       quant=True)) if control else None)
         for j, out in enumerate(served):
             n = min(len(out), ref.shape[1])
             g = gaps(ref[j, :n], out[:n])

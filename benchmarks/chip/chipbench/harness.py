@@ -25,9 +25,10 @@ import threading
 import time
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import Callable
 
-from . import check, system, trace as trace_mod, traffic as traffic_mod, weights
+from . import blocks, check, system, trace as trace_mod, traffic as traffic_mod, weights
 from .cell import HERE, ROOT, Cell, reader
 from .peaks import peaks_for
 
@@ -42,7 +43,8 @@ class NoChip(RuntimeError):
 class Run:
     """What one window left behind; the per-layer metrics read it."""
 
-    dims: weights.Dims
+    block: ModuleType | None  # blocks/<block>.py of the configuration
+    dims: object  # the block's Dims
     peaks: dict | None
     seconds: float
     t0_wall: float
@@ -252,11 +254,13 @@ class Session:
         import jax
 
         self.cell, self.variant, self.workdir = cell, variant, workdir
+        self.block = blocks.of(cell.config)
+        self.arch = system.register(cell.config)
         self.mc = system.model_config(cell.config, variant)
-        self.dims = system.dims_of(self.mc)
-        if variant == "full" and self.dims != weights.Dims.from_config(cell.config):
+        self.dims = self.block.dims_of(self.mc)
+        if variant == "full" and self.dims != self.block.Dims.from_config(cell.config):
             raise ValueError(f"the program builds {self.dims}, the configuration file "
-                             f"states {weights.Dims.from_config(cell.config)}")
+                             f"states {self.block.Dims.from_config(cell.config)}")
         self.dtype = jax.numpy.dtype(self.mc.param_dtype)
         self.calls: list[dict] = []
         self.phases: dict[str, float] = {}  # set-up seconds by phase
@@ -267,8 +271,10 @@ class Session:
             jax.block_until_ready(params)
             self.phases["weights"] = time.time() - t
             self.worker = self.colony.start_executor(
-                self.mc.name, variant, params, int(cell.traffic["max_len"]))
+                self.arch, variant, params, int(cell.traffic["max_len"]))
             self.engine = self.worker.engine
+            if self.engine.cfg != self.mc:
+                raise ValueError(f"the executor built {self.engine.cfg}, not {self.mc}")
             _instrument(self.engine, self.calls)
             t = time.time()
             warm(self.engine, cell.traffic)
@@ -279,9 +285,9 @@ class Session:
             raise
 
     def make_params(self, seed: int) -> dict:
-        w = weights.make(self.dims, traffic_mod.seed32(seed, traffic_mod.STREAM_WEIGHTS),
-                         self.dtype)
-        return system.program_params(w, self.dims, self.mc)
+        w = weights.make(self.block.layout(self.dims),
+                         traffic_mod.seed32(seed, traffic_mod.STREAM_WEIGHTS), self.dtype)
+        return self.block.program_params(w, self.dims, self.mc)
 
     def set_weights(self, seed: int) -> None:
         """Serve the weights of another seed (the programs stay warm)."""
@@ -381,7 +387,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *, t_start: fl
         verdict = verify(session, win["records"], seed, seconds)
         after["check"] = time.time() - t
 
-    run = Run(dims=session.dims,
+    run = Run(block=session.block, dims=session.dims,
               peaks=peaks_for(devices[0].device_kind) if platform == "tpu" else None,
               seconds=seconds, t0_wall=win["t0"], end_wall=win["end_wall"],
               records=win["records"], processes=win["processes"], calls=win["calls"],
@@ -418,7 +424,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *, t_start: fl
         line["breakdown"] = {"device_ops": tr["device_ops"], "idle_gaps": tr["idle_gaps"]}
     line["checked"] = checked
     lags = [r["lag"] for r in recs if r["lag"] is not None]
+    cfg = session.engine.cfg
     notes = [
+        f"served: {cfg.name}, {cfg.num_layers} layers, {cfg.param_dtype}",
         "set-up: " + ", ".join(f"{k} {v:.3f} s" for k, v in session.phases.items()),
         "after the window: " + ", ".join(f"{k} {v:.3f} s" for k, v in after.items()),
         f"requests: attempted {len(recs)} answered {len(recs) - failed - unanswered} "
@@ -455,13 +463,14 @@ def verify(session: Session, records: list[dict], seed: int, seconds: float,
            control: bool = False) -> dict:
     """The reference over a sample of the window's answers (the program's
     weights must be freed first: the reference makes its own)."""
-    traffic, dims = session.cell.traffic, session.dims
+    traffic, block, dims = session.cell.traffic, session.block, session.dims
     requests = traffic_mod.schedule(traffic, seed, seconds, dims.vocab)
     picked = check.sample(records, seed, int(traffic["check"]["tokens"]))
     pad_to = int(traffic["prompt_tokens"]) + int(traffic["output_tokens"]["max"]) - 1
-    w = weights.make(dims, traffic_mod.seed32(seed, traffic_mod.STREAM_WEIGHTS), session.dtype)
+    w = weights.make(block.layout(dims), traffic_mod.seed32(seed, traffic_mod.STREAM_WEIGHTS),
+                     session.dtype)
     try:
-        return check.compare(w, dims, requests, picked, pad_to,
+        return check.compare(block, w, dims, requests, picked, pad_to,
                              int(traffic["check"]["rows_per_call"]), control=control)
     finally:
         _free(w)

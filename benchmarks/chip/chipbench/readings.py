@@ -48,7 +48,7 @@ def roofline_share(run, kind: str, program: str) -> float | None:
     if run.trace is None or run.peaks is None:
         return None
     device = mean(run.trace["calls"].get(program, []))
-    least = mean([work.roofline_s(run.dims, k, b, n, run.peaks)
+    least = mean([work.roofline_s(run.block, run.dims, k, b, n, run.peaks)
                   for k, b, n in model_calls(run, kind)])
     return None if device is None or least is None else 100.0 * least / device
 
@@ -58,5 +58,5 @@ def mfu(run) -> float | None:
     traced window's seconds times the chip's peak bf16 rate, in percent."""
     if run.trace is None or run.peaks is None or not run.calls:
         return None
-    ops = sum(work.flops(run.dims, k, b, n) for k, b, n in model_calls(run))
+    ops = sum(run.block.flops(run.dims, k, b, n) for k, b, n in model_calls(run))
     return 100.0 * ops / (run.trace["window_s"] * run.peaks["bf16_flops_per_s"])

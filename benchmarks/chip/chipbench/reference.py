@@ -1,17 +1,11 @@
-"""Plain float32 reference of a dense GQA decoder, and its fp8 control.
+"""What the plain float32 references of the blocks share.
 
-Written from the layer equations, not from the program: it imports
-nothing of ``src/repro``. It follows the architecture as the repository
-declares it (the configuration files list where that departs from the
-published model): pre-norm blocks; LayerNorm or RMSNorm; rotary
-embedding over the whole head in rotate-half form; causal softmax
-attention scaled by head_dim ** -0.5, query heads grouped over the
-key/value heads; a SwiGLU MLP, silu(x W_gate) * (x W_up) W_down; a final
-norm and an output head (the embedding's transpose when tied).
-
-Every matrix product runs at ``Precision.HIGHEST`` in float32. The
-whole sequence goes through at once, with no cache, one layer per call
-and queries in blocks, so that it fits beside nothing else on the chip.
+Each block module (``blocks/<block>.py``) writes its reference from the
+layer equations with these helpers, not from the program: none of them
+imports anything of ``src/repro``. Every matrix product runs at
+``Precision.HIGHEST`` in float32. The whole sequence goes through at
+once, with no cache, and attention takes its queries in blocks, so that
+a reference run layer by layer fits beside nothing else on the chip.
 
 ``quant=True`` is the control: every matrix product takes its operands
 rounded to float8 e4m3 (per-row scales for activations, per-column
@@ -21,13 +15,9 @@ configurations state. Norms, softmax and accumulation stay float32.
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from .weights import Dims
 
 Q_BLOCK = 512
 FP8_MAX = 448.0  # largest finite float8_e4m3fn
@@ -53,12 +43,14 @@ def _mm(spec: str, a: jax.Array, b: jax.Array, quant: bool) -> jax.Array:
                       preferred_element_type=jnp.float32)
 
 
-def _norm(x: jax.Array, scale: jax.Array, bias: jax.Array | None, dims: Dims) -> jax.Array:
-    if dims.norm == "layernorm":
+def _norm(x: jax.Array, scale: jax.Array, bias: jax.Array | None, kind: str,
+          eps: float) -> jax.Array:
+    """``kind`` "layernorm" (with ``bias``) or "rmsnorm"."""
+    if kind == "layernorm":
         mu = x.mean(-1, keepdims=True)
         var = ((x - mu) ** 2).mean(-1, keepdims=True)
-        return (x - mu) / jnp.sqrt(var + dims.norm_eps) * scale + bias
-    return x / jnp.sqrt((x ** 2).mean(-1, keepdims=True) + dims.norm_eps) * scale
+        return (x - mu) / jnp.sqrt(var + eps) * scale + bias
+    return x / jnp.sqrt((x ** 2).mean(-1, keepdims=True) + eps) * scale
 
 
 def _rope(x: jax.Array, theta: float) -> jax.Array:
@@ -88,38 +80,6 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array, quant: bool) -> jax.Arr
     return jnp.concatenate(outs, axis=1)
 
 
-@partial(jax.jit, static_argnums=(2, 3))
-def _block(w: dict, x: jax.Array, dims: Dims, quant: bool) -> jax.Array:
-    w = {k: a.astype(jnp.float32) for k, a in w.items()}
-    h = _norm(x, w["norm1.scale"], w.get("norm1.bias"), dims)
-    q = _rope(_mm("btd,dhk->bthk", h, w["wq"], quant), dims.rope_theta)
-    k = _rope(_mm("btd,dhk->bthk", h, w["wk"], quant), dims.rope_theta)
-    v = _mm("btd,dhk->bthk", h, w["wv"], quant)
-    x = x + _mm("bthk,hkd->btd", _attention(q, k, v, quant), w["wo"], quant)
-    h = _norm(x, w["norm2.scale"], w.get("norm2.bias"), dims)
-    gate = _mm("btd,df->btf", h, w["w_gate"], quant)
-    up = _mm("btd,df->btf", h, w["w_up"], quant)
-    return x + _mm("btf,fd->btd", jax.nn.silu(gate) * up, w["w_down"], quant)
-
-
-@partial(jax.jit, static_argnums=(3, 4, 5))
-def _head(w: dict, x: jax.Array, embed: jax.Array, start: int, dims: Dims, quant: bool):
-    h = _norm(x[:, start:], w["norm_f.scale"].astype(jnp.float32),
-              w["norm_f.bias"].astype(jnp.float32) if "norm_f.bias" in w else None, dims)
-    head = embed.T if dims.tied else w["head"]
-    return _mm("btd,dv->btv", h, head.astype(jnp.float32), quant)
-
-
 @jax.jit
 def _embed(embed: jax.Array, tokens: jax.Array) -> jax.Array:
     return embed[tokens].astype(jnp.float32)
-
-
-def logits(w: dict, dims: Dims, tokens: jax.Array, start: int, quant: bool = False) -> jax.Array:
-    """Logits (B, T - start, V) of positions start..T-1 of ``tokens`` (B, T)."""
-    x = _embed(w["embed"], tokens)
-    for layer in range(dims.layers):
-        lw = {k.removeprefix("layers."): a[layer] for k, a in w.items() if k.startswith("layers.")}
-        x = _block(lw, x, dims, quant)
-    tail = {k: a for k, a in w.items() if k.startswith(("norm_f.", "head"))}
-    return _head(tail, x, w["embed"], start, dims, quant)

@@ -2,7 +2,8 @@
 
 This is the one module of the harness that knows the program's
 interfaces: its architecture registry, the executor it serves with, the
-layout of its parameters and the colony around it. It starts what
+check of a parameter tree against the program's own, and the colony
+around it (a block module maps its weights onto that tree). It starts what
 ``launch/serve.py``'s ``serve()`` starts (a standalone server with its
 failsafe ticking, a ``ServeExecutor``, a generator that batches packs)
 and serves the colony over the HTTP transport as well, so that the load
@@ -12,12 +13,11 @@ generator reaches it from another process as a remote user would.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import threading
 import types
 
 import jax
-
-from .weights import Dims
 
 COLONY = "bench"
 EXECUTOR_TYPE = "tpu-serve"
@@ -27,8 +27,12 @@ BATCH_MAXEXEC_S = 300
 def register(config: dict) -> str:
     """Make the configuration's architecture known to the program's
     registry and return its id. A cut of a registered architecture
-    (``program.base_arch`` with ``program.overrides``) is registered
-    under its own id; the executor then builds it through its normal path."""
+    (``program.base_arch`` with ``program.overrides``) is registered under
+    its own id, and its ``ModelConfig`` is named by that id: the executor,
+    built from the id, builds the cut. An override that holds a dict goes
+    into the nested config of that name field by field, so that
+    ``{"moe": {"num_experts": 8}}`` keeps the rest of the base's ``moe``.
+    The smoke variant is the base's smoke model under the cut's id."""
     from repro.configs import ARCHS, get_config
 
     prog = config["program"]
@@ -37,9 +41,24 @@ def register(config: dict) -> str:
         base, overrides = prog["base_arch"], dict(prog.get("overrides", {}))
         ARCHS[arch] = types.SimpleNamespace(
             ARCH_ID=arch,
-            full=lambda: get_config(base, "full").copy(**overrides),
-            smoke=lambda: get_config(base, "smoke"))
+            full=lambda: _override(get_config(base, "full"), dict(overrides, name=arch)),
+            smoke=lambda: get_config(base, "smoke").copy(name=arch))
     return arch
+
+
+def _override(obj, overrides: dict):
+    """``obj`` (a dataclass) with ``overrides`` applied, into nested
+    dataclasses where an override is a dict."""
+    fields = {f.name for f in dataclasses.fields(obj)}
+    kw = {}
+    for key, value in overrides.items():
+        if key not in fields:
+            raise KeyError(f"{type(obj).__name__} has no field {key!r}")
+        inner = getattr(obj, key)
+        if isinstance(value, dict) and dataclasses.is_dataclass(inner):
+            value = _override(inner, value)
+        kw[key] = value
+    return dataclasses.replace(obj, **kw)
 
 
 def model_config(config: dict, variant: str):
@@ -49,39 +68,12 @@ def model_config(config: dict, variant: str):
     return executor_config(register(config), variant)
 
 
-def dims_of(mc) -> Dims:
-    if mc.family != "dense" or mc.attention != "gqa" or mc.activation != "swiglu":
-        raise ValueError(f"{mc.name}: the harness serves dense GQA SwiGLU models only")
-    if mc.qkv_bias or not mc.use_rope or mc.sliding_window:
-        raise ValueError(f"{mc.name}: qkv bias, no rotary or a window is not modelled")
-    return Dims(layers=mc.num_layers, d_model=mc.d_model, heads=mc.num_heads,
-                kv_heads=mc.num_kv_heads, head_dim=mc.head_dim, d_ff=mc.d_ff,
-                vocab=mc.vocab_size, tied=mc.tied_embeddings, norm=mc.norm,
-                norm_eps=mc.norm_eps, rope_theta=mc.rope_theta)
-
-
-def program_params(w: dict, dims: Dims, mc) -> dict:
-    """The benchmark's weights in the program's parameter tree (the same
-    buffers, no copy), checked leaf by leaf against the tree the program
-    would build for itself."""
+def checked(params: dict, mc) -> dict:
+    """``params``, once checked leaf by leaf (structure, shapes, dtypes)
+    against the tree the program would build for ``mc`` itself."""
     from repro.models import model_spec
     from repro.models.sharding import abstract_params
 
-    def norm(prefix: str) -> dict:
-        out = {"scale": w[f"{prefix}.scale"]}
-        if dims.norm == "layernorm":
-            out["bias"] = w[f"{prefix}.bias"]
-        return out
-
-    block = {
-        "norm1": norm("layers.norm1"), "norm2": norm("layers.norm2"),
-        "mixer": {k: w[f"layers.{k}"] for k in ("wq", "wk", "wv", "wo")},
-        "mlp": {"w_gate": w["layers.w_gate"], "w_in": w["layers.w_up"],
-                "w_out": w["layers.w_down"]},
-    }
-    params = {"embed": w["embed"], "groups": {"b0": block}, "norm_f": norm("norm_f")}
-    if not dims.tied:
-        params["lm_head"] = w["head"]
     want = abstract_params(model_spec(mc), jax.numpy.dtype(mc.param_dtype))
     got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
     if jax.tree.structure(got) != jax.tree.structure(want) or jax.tree.leaves(got) != \

@@ -37,15 +37,16 @@ def control(engine):
     # the fp8 control in the program's place: at each served position, the
     # token that the reference computed from fp8 operands puts first
     import jax.numpy as jnp
-    from chipbench import reference, system, traffic, weights
-    dims = system.dims_of(engine.cfg)
-    w = weights.make(dims, traffic.seed32(SEED, traffic.STREAM_WEIGHTS),
+    from chipbench import blocks, traffic, weights
+    block = blocks.of(cell.config)
+    dims = block.dims_of(engine.cfg)
+    w = weights.make(block.layout(dims), traffic.seed32(SEED, traffic.STREAM_WEIGHTS),
                      jnp.dtype(engine.cfg.param_dtype))
     inner = engine.generate
     def generate(tokens, max_new_tokens=16, *a, **k):
         out = np.asarray(inner(tokens, max_new_tokens, *a, **k))
         seq = jnp.asarray(np.concatenate([tokens, out[:, :-1]], 1))
-        ctl = reference.logits(w, dims, seq, tokens.shape[1] - 1, quant=True)
+        ctl = block.logits(w, dims, seq, tokens.shape[1] - 1, quant=True)
         return np.asarray(ctl).argmax(-1).astype(out.dtype)
     engine.generate = generate
 

@@ -7,14 +7,14 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from chipbench import work  # noqa: E402
+from chipbench import blocks, work  # noqa: E402
 from chipbench.cell import reader  # noqa: E402
 from chipbench.harness import Run, output_tokens_per_s, percentile  # noqa: E402
 from chipbench.peaks import peaks_for  # noqa: E402
-from chipbench.weights import Dims  # noqa: E402
 
-D = Dims(layers=2, d_model=64, heads=4, kv_heads=2, head_dim=16, d_ff=208, vocab=259,
-         tied=True, norm="rmsnorm", norm_eps=1e-5, rope_theta=1e4)
+dense = blocks.load("dense")
+D = dense.Dims(layers=2, d_model=64, heads=4, kv_heads=2, head_dim=16, d_ff=208, vocab=259,
+               tied=True, norm="rmsnorm", norm_eps=1e-5, rope_theta=1e4)
 T0 = 1000.0
 
 
@@ -47,7 +47,7 @@ def _run(trace=None):
             "starttime": int((T0 + 2.1) * 1e9), "endtime": int((T0 + 2.9) * 1e9),
             "spec": {"kwargs": {"packed_args": [{"request_id": "a"}, {"request_id": "b"}]}}}
     calls = [{"start": T0 + 2.2, "end": T0 + 2.7, "batch": 2, "seq": 8, "new": 4}]
-    return Run(dims=D, peaks=peaks_for("TPU v5 lite"), seconds=10.0, t0_wall=T0,
+    return Run(block=dense, dims=D, peaks=peaks_for("TPU v5 lite"), seconds=10.0, t0_wall=T0,
                end_wall=T0 + 12, records=recs, processes=[proc], calls=calls,
                compiles_in_window=0, memory_peak_bytes=12_345_000_000, trace=trace)
 
@@ -77,10 +77,10 @@ def test_trace_readers():
     assert reader("prefill_device_ms")(run) == pytest.approx(2.0)
     assert reader("decode_step_device_ms")(run) == pytest.approx(5 / 3)
     peaks = run.peaks
-    decodes = [work.roofline_s(D, "decode", 2, p, peaks) for p in (8, 9, 10)]
+    decodes = [work.roofline_s(dense, D, "decode", 2, p, peaks) for p in (8, 9, 10)]
     assert reader("decode_step_roofline")(run) == pytest.approx(100 * (sum(decodes) / 3) / (0.005 / 3))
     assert reader("prefill_roofline")(run) == pytest.approx(
-        100 * work.roofline_s(D, "prefill", 2, 8, peaks) / 0.002)
-    ops = work.prefill_flops(D, 2, 8) + sum(work.decode_flops(D, 2, p) for p in (8, 9, 10))
+        100 * work.roofline_s(dense, D, "prefill", 2, 8, peaks) / 0.002)
+    ops = dense.prefill_flops(D, 2, 8) + sum(dense.decode_flops(D, 2, p) for p in (8, 9, 10))
     assert reader("step_mfu")(run) == pytest.approx(100 * ops / (2.0 * 197e12))
     assert reader("step_mfu.latency")(run) == reader("step_mfu")(run)

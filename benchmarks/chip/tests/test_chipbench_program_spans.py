@@ -15,21 +15,21 @@ import pytest
 BENCH = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(BENCH), str(BENCH.parents[1] / "src")]
 
-from chipbench import program_spans, trace  # noqa: E402
+from chipbench import blocks, program_spans, trace  # noqa: E402
 from chipbench.cell import reader  # noqa: E402
 from chipbench.harness import CompileCounter, Run  # noqa: E402
-from chipbench.weights import Dims  # noqa: E402
 
 TESTDATA = BENCH / "testdata"
-D = Dims(layers=2, d_model=64, heads=4, kv_heads=2, head_dim=16, d_ff=208, vocab=259,
-         tied=True, norm="rmsnorm", norm_eps=1e-5, rope_theta=1e4)
+dense = blocks.load("dense")
+D = dense.Dims(layers=2, d_model=64, heads=4, kv_heads=2, head_dim=16, d_ff=208, vocab=259,
+               tied=True, norm="rmsnorm", norm_eps=1e-5, rope_theta=1e4)
 IDLE = ("idle_no_batch_share", "idle_executor_share", "idle_engine_share")
 NEW = IDLE + ("generator_hold_s", "signature_cpu_share", "compile_s_in_window")
 MS = 1_000_000
 
 
 def _run(end_wall, window_s, busy_s):
-    return Run(dims=D, peaks=None, seconds=window_s, t0_wall=end_wall - window_s,
+    return Run(block=dense, dims=D, peaks=None, seconds=window_s, t0_wall=end_wall - window_s,
                end_wall=end_wall, records=[], processes=[], calls=[], compiles_in_window=0,
                memory_peak_bytes=None, trace={"busy_s": busy_s, "window_s": window_s})
 

@@ -7,9 +7,8 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from chipbench import traffic  # noqa: E402
+from chipbench import blocks, system, traffic  # noqa: E402
 from chipbench.cell import HERE, ROOT, load, reader  # noqa: E402
-from chipbench.weights import Dims  # noqa: E402
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -23,7 +22,10 @@ def test_names_and_files():
         conf = json.loads((ROOT / c["file"]).read_text())
         assert set(c["reduced"]) == set(conf["changed_from_source"])
         assert not set(conf["departures"]) & set(conf["changed_from_source"])
-        assert Dims.from_config(conf).layers == conf["num_hidden_layers"]
+        block = blocks.of(conf)
+        dims = block.Dims.from_config(conf)
+        assert dims.vocab == conf["vocab_size"]
+        assert block.dims_of(system.model_config(conf, "full")) == dims  # the program runs the file
     for w in SPEC["workloads"]:
         t = json.loads((HERE / "traffic" / f"{w['traffic']}.json").read_text())
         assert len(traffic.schedule(t, 1, 5, 100)) == round(5 * t["rate_per_s"])
